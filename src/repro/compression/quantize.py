@@ -17,11 +17,6 @@ class QuantizedTensor:
     group: int
     shape: tuple
     dtype: str = "float32"
-    # per-group value range hi - lo (clamped). Bit-width independent, so
-    # the mixed-bitwidth dequant path can re-derive any width's step as
-    # spans / (2^bits - 1) from one shared parameter plane. None on
-    # tensors quantized before this field existed.
-    spans: np.ndarray = None
 
     @property
     def n_symbols(self) -> int:
@@ -55,8 +50,7 @@ def quantize(x: np.ndarray, bits: int, group: int) -> QuantizedTensor:
     return QuantizedTensor(codes=codes.reshape(-1)[:int(np.prod(shape))],
                            scales=scales.astype(np.float32),
                            zeros=lo.astype(np.float32),
-                           bits=bits, group=group, shape=tuple(shape),
-                           spans=span.astype(np.float32))
+                           bits=bits, group=group, shape=tuple(shape))
 
 
 def dequantize(qt: QuantizedTensor) -> np.ndarray:

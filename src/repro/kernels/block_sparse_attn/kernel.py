@@ -10,7 +10,7 @@ grid dimension.
 Grid: (batch*q_heads, n_q_blocks, max_active_blocks). TPU grid iteration
 is sequential over the last dimension, which makes the scratch-carried
 softmax recurrence legal; `interpret=True` preserves those semantics on
-CPU for validation.
+CPU for validation. `interpret` is required: the caller chooses.
 """
 from __future__ import annotations
 
@@ -82,7 +82,7 @@ def _kernel(idx_ref, cnt_ref,                      # scalar prefetch
 def block_sparse_attention(q, k, v, block_idx, block_cnt, *,
                            causal: bool = True, q_block: int = 128,
                            kv_block: int = 128, scale: float | None = None,
-                           kv_group: int = 1, interpret: bool = True):
+                           kv_group: int = 1, interpret: bool):
     """q: (bh, sq, d); k/v: (bh_kv, skv, d) with bh == bh_kv * kv_group
     (GQA: q row bh reads kv row bh // kv_group).
     block_idx: (bh, n_qb, max_nnz) int32; block_cnt: (bh, n_qb) int32.

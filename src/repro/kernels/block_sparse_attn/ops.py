@@ -2,22 +2,18 @@
 
 `sparse_prefill_attention` is the full pipeline the serving engine uses
 for locally-computed chunks: estimate block importance -> select blocks at
-98% mass -> run the Pallas kernel (interpret=True on CPU, compiled on
-TPU). The pure-jnp oracle lives in ref.py.
+98% mass -> run the Pallas kernel (interpret mode as
+`repro.kernels.pallas_interpret` resolves it). The pure-jnp oracle lives
+in ref.py.
 """
 from __future__ import annotations
 
-
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import pallas_interpret
 from repro.kernels.block_sparse_attn.kernel import block_sparse_attention
 from repro.kernels.block_sparse_attn.ref import block_sparse_attention_ref
 from repro.sparse.mask import block_scores, select_blocks
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def sparse_prefill_attention(q, k, v, *, mass: float = 0.98,
@@ -47,9 +43,9 @@ def sparse_prefill_attention(q, k, v, *, mass: float = 0.98,
                                        causal=causal, q_block=q_block,
                                        kv_block=kv_block)
     else:
-        interp = (not _on_tpu()) if interpret is None else interpret
         o = block_sparse_attention(qf, kf, vf, idx, cnt, causal=causal,
                                    q_block=q_block, kv_block=kv_block,
-                                   kv_group=g, interpret=interp)
+                                   kv_group=g,
+                                   interpret=pallas_interpret(interpret))
     o = o.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
     return o, cnt.reshape(b, hq, s // q_block)

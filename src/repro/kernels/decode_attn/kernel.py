@@ -63,7 +63,7 @@ def _kernel(len_ref,                               # scalar prefetch
 @functools.partial(jax.jit,
                    static_argnames=("kv_block", "scale", "interpret"))
 def decode_attention(q, k, v, kv_len, *, kv_block: int = 256,
-                     scale: float | None = None, interpret: bool = True):
+                     scale: float | None = None, interpret: bool):
     """q: (b, hq, d); k/v: (b, skv, hkv, d); kv_len: int32 scalar.
     Returns (b, hq, d)."""
     b, hq, d = q.shape

@@ -6,8 +6,18 @@ on-chip so the host never materializes an fp32 copy (on the paper's edge
 path this was the PCIe-attached "device transfer" slice of Fig. 16 — on
 TPU the dequant runs where the cache lives).
 
-Rows are tiled in VMEM-sized blocks; the group dimension stays inside a
-row so a (rows_blk, width) tile always holds whole groups.
+Layout: one quantization group per row. codes are (n_groups, group)
+uint8 and the affine parameters are (n_groups, 1) fp32 columns, so the
+per-group broadcast is a lane broadcast and the kernel needs no
+in-register reshape (Mosaic refuses a (rows, g*group) -> (rows, g, group)
+shape cast). The codes go uint8 -> int32 -> fp32: Mosaic has no direct
+uint8 -> fp32 cast. Rows are tiled in VMEM-sized blocks.
+
+Rows are independent, so one launch dequantizes chunks of different bit
+widths: each row's step is the host-computed scale quantize() stored.
+The step is never derived on the chip (span / (2^bits - 1)): TPU fp32
+division is not correctly rounded, and such a row would differ by an
+ulp from the same chunk dequantized alone.
 """
 from __future__ import annotations
 
@@ -18,82 +28,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(c_ref, s_ref, z_ref, o_ref, *, group: int):
-    rows, width = c_ref.shape
-    g = width // group
-    c = c_ref[...].astype(jnp.float32).reshape(rows, g, group)
-    x = c * s_ref[...][..., None] + z_ref[...][..., None]
-    o_ref[...] = x.reshape(rows, width).astype(o_ref.dtype)
-
-
-def _mixed_kernel(c_ref, s_ref, z_ref, b_ref, o_ref, *, group: int):
-    rows, width = c_ref.shape
-    g = width // group
-    c = c_ref[...].astype(jnp.float32).reshape(rows, g, group)
-    # per-row bits plane selects the scale interpretation: s_ref holds
-    # the bit-width-independent per-group value SPAN (hi - lo), and the
-    # row's width turns it into the affine step span / (2^bits - 1) —
-    # one launch dequantizes rows of heterogeneous widths
-    q = ((1 << b_ref[...].astype(jnp.int32)) - 1).astype(jnp.float32)
-    step = s_ref[...] / q                       # (rows, g) / (rows, 1)
-    x = c * step[..., None] + z_ref[...][..., None]
-    o_ref[...] = x.reshape(rows, width).astype(o_ref.dtype)
+def _kernel(c_ref, s_ref, z_ref, o_ref):
+    c = c_ref[...].astype(jnp.int32).astype(jnp.float32)
+    o_ref[...] = (c * s_ref[...] + z_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("group", "rows_blk", "interpret",
-                                    "out_dtype"))
-def kv_dequant_mixed(codes, spans, zeros, bits, *, group: int = 64,
-                     rows_blk: int = 256, interpret: bool = True,
-                     out_dtype=jnp.bfloat16):
-    """Mixed-bitwidth variant: rows may carry different quantization
-    widths. codes: (n, width) uint8, width % group == 0; spans/zeros:
-    (n, width//group) float32 per-group value range / offset; bits:
-    (n, 1) int32 per-row widths. A row's step is spans / (2^bits - 1) —
-    computed in fp32, so a uniform-bits launch is bit-identical to
-    `kv_dequant` fed the host-computed scales (same IEEE division)."""
-    n, width = codes.shape
-    g = width // group
+                   static_argnames=("rows_blk", "interpret", "out_dtype"))
+def kv_dequant(codes, scales, zeros, *, interpret: bool,
+               rows_blk: int = 512, out_dtype=jnp.bfloat16):
+    """codes: (n, group) uint8, one group per row; scales/zeros: (n, 1)
+    float32 -> (n, group) out_dtype."""
+    n, group = codes.shape
     rows_blk = min(rows_blk, n)
-    grid = (-(-n // rows_blk),)
-    kern = functools.partial(_mixed_kernel, group=group)
+    row = lambda i: (i, 0)
     return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rows_blk, width), lambda i: (i, 0)),
-            pl.BlockSpec((rows_blk, g), lambda i: (i, 0)),
-            pl.BlockSpec((rows_blk, g), lambda i: (i, 0)),
-            pl.BlockSpec((rows_blk, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows_blk, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, width), out_dtype),
-        interpret=interpret,
-    )(codes, spans, zeros, bits)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("group", "rows_blk", "interpret",
-                                    "out_dtype"))
-def kv_dequant(codes, scales, zeros, *, group: int = 64,
-               rows_blk: int = 256, interpret: bool = True,
-               out_dtype=jnp.bfloat16):
-    """codes: (n, width) uint8, width % group == 0;
-    scales/zeros: (n, width//group) float32 -> (n, width) out_dtype."""
-    n, width = codes.shape
-    g = width // group
-    rows_blk = min(rows_blk, n)
-    grid = (-(-n // rows_blk),)
-    kern = functools.partial(_kernel, group=group)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rows_blk, width), lambda i: (i, 0)),
-            pl.BlockSpec((rows_blk, g), lambda i: (i, 0)),
-            pl.BlockSpec((rows_blk, g), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((rows_blk, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, width), out_dtype),
+        _kernel,
+        grid=(-(-n // rows_blk),),
+        in_specs=[pl.BlockSpec((rows_blk, group), row),
+                  pl.BlockSpec((rows_blk, 1), row),
+                  pl.BlockSpec((rows_blk, 1), row)],
+        out_specs=pl.BlockSpec((rows_blk, group), row),
+        out_shape=jax.ShapeDtypeStruct((n, group), out_dtype),
         interpret=interpret,
     )(codes, scales, zeros)

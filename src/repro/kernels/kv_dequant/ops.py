@@ -1,98 +1,49 @@
-"""Wrapper: QuantizedTensor (wire format) -> device KV tensor via the
-fused Pallas dequant kernel."""
+"""Wrapper: QuantizedTensor (wire format) -> KV values via the fused
+Pallas dequant kernel."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.compression.quantize import QuantizedTensor
-from repro.kernels.kv_dequant.kernel import kv_dequant, kv_dequant_mixed
+from repro.kernels import pallas_interpret
+from repro.kernels.kv_dequant.kernel import kv_dequant
 
 
-def dequantize_chunk(qt: QuantizedTensor, *, interpret: bool | None = None,
-                     out_dtype=jnp.bfloat16):
-    """Dequantize a streamed KV chunk on-device. Returns qt.shape array."""
+def group_rows(qt: QuantizedTensor) -> np.ndarray:
+    """The kernel's row layout: (n_groups, group) uint8, one quantization
+    group per row, the tail group zero-padded."""
     n_vals = int(np.prod(qt.shape))
-    group = qt.group
-    g_total = qt.scales.shape[0]
-    codes = np.zeros(g_total * group, np.uint8)
+    codes = np.zeros(qt.scales.shape[0] * qt.group, np.uint8)
     codes[:n_vals] = qt.codes
-    # row layout: pack whole groups per row, <= 8 groups/row
-    gpr = max(1, min(8, g_total))
-    rows = -(-g_total // gpr)
-    pad_g = rows * gpr - g_total
-    codes = codes.reshape(g_total, group)
-    scales, zeros = qt.scales, qt.zeros
-    if pad_g:
-        codes = np.concatenate([codes, np.zeros((pad_g, group), np.uint8)])
-        scales = np.concatenate([scales, np.ones(pad_g, np.float32)])
-        zeros = np.concatenate([zeros, np.zeros(pad_g, np.float32)])
-    codes = codes.reshape(rows, gpr * group)
-    scales = scales.reshape(rows, gpr)
-    zeros = zeros.reshape(rows, gpr)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
-    out = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
-                     jnp.asarray(zeros), group=group, interpret=interp,
-                     out_dtype=out_dtype)
-    return out.reshape(-1)[:n_vals].reshape(qt.shape)
+    return codes.reshape(-1, qt.group)
 
 
-def _spans_of(qt: QuantizedTensor) -> np.ndarray:
-    if qt.spans is not None:
-        return qt.spans
-    # pre-spans tensors: reconstruct (scales were span / (2^bits - 1))
-    return (qt.scales * np.float32((1 << qt.bits) - 1)).astype(np.float32)
-
-
-def dequantize_chunks_mixed(qts: list, *, interpret: bool | None = None,
-                            out_dtype=jnp.bfloat16) -> list:
-    """Dequantize many streamed KV chunks of heterogeneous bit-widths in
-    ONE kernel launch (per-chunk adaptive quantization's fast path: the
-    assembly loop would otherwise launch once per bits bucket). All
-    chunks must share the quantization group size; each chunk's groups
-    are packed into rows carrying that chunk's bit-width in the per-row
-    bits plane. Returns one qt.shape array per input, each exactly equal
-    (in fp32) to its per-chunk `dequantize_chunk` launch."""
+def dequantize_chunks(qts: list, *, interpret: bool | None = None,
+                      out_dtype=jnp.bfloat16) -> list:
+    """Dequantize streamed KV chunks, of any mix of bit widths, in ONE
+    kernel launch: each chunk's groups become rows carrying their own
+    scale and zero. All chunks must share the quantization group size.
+    Returns one qt.shape numpy array per input; a chunk's values do not
+    depend on which other chunks share the launch."""
     assert qts, "empty chunk list"
     group = qts[0].group
     assert all(q.group == group for q in qts), "heterogeneous group size"
-    gpr = max(1, min(8, max(q.scales.shape[0] for q in qts)))
-    codes_rows, span_rows, zero_rows, bits_rows = [], [], [], []
-    for qt in qts:
-        g_total = qt.scales.shape[0]
-        n_vals = int(np.prod(qt.shape))
-        codes = np.zeros(g_total * group, np.uint8)
-        codes[:n_vals] = qt.codes
-        rows = -(-g_total // gpr)
-        pad_g = rows * gpr - g_total
-        codes = codes.reshape(g_total, group)
-        spans, zeros = _spans_of(qt), qt.zeros
-        if pad_g:
-            codes = np.concatenate(
-                [codes, np.zeros((pad_g, group), np.uint8)])
-            spans = np.concatenate([spans, np.ones(pad_g, np.float32)])
-            zeros = np.concatenate([zeros, np.zeros(pad_g, np.float32)])
-        codes_rows.append(codes.reshape(rows, gpr * group))
-        span_rows.append(spans.reshape(rows, gpr))
-        zero_rows.append(zeros.reshape(rows, gpr))
-        bits_rows.append(np.full((rows, 1), qt.bits, np.int32))
-    starts = np.cumsum([0] + [b.shape[0] for b in codes_rows])
-    codes_all = np.concatenate(codes_rows)
-    spans_all = np.concatenate(span_rows).astype(np.float32)
-    zeros_all = np.concatenate(zero_rows).astype(np.float32)
-    bits_all = np.concatenate(bits_rows)
-    interp = (jax.default_backend() != "tpu") if interpret is None \
-        else interpret
-    out = kv_dequant_mixed(jnp.asarray(codes_all), jnp.asarray(spans_all),
-                           jnp.asarray(zeros_all), jnp.asarray(bits_all),
-                           group=group, interpret=interp,
-                           out_dtype=out_dtype)
-    out = np.asarray(out)
-    results = []
-    for i, qt in enumerate(qts):
-        n_vals = int(np.prod(qt.shape))
-        rows = out[starts[i]:starts[i + 1]]
-        results.append(rows.reshape(-1)[:n_vals].reshape(qt.shape))
-    return results
+    codes = np.concatenate([group_rows(qt) for qt in qts])
+    scales = np.concatenate([qt.scales for qt in qts]).astype(np.float32)
+    zeros = np.concatenate([qt.zeros for qt in qts]).astype(np.float32)
+    out = np.asarray(kv_dequant(jnp.asarray(codes),
+                                jnp.asarray(scales[:, None]),
+                                jnp.asarray(zeros[:, None]),
+                                interpret=pallas_interpret(interpret),
+                                out_dtype=out_dtype))
+    starts = np.cumsum([0] + [qt.scales.shape[0] for qt in qts])
+    return [out[a:b].reshape(-1)[:int(np.prod(qt.shape))].reshape(qt.shape)
+            for qt, a, b in zip(qts, starts, starts[1:])]
+
+
+def dequantize_chunk(qt: QuantizedTensor, *, interpret: bool | None = None,
+                     out_dtype=jnp.bfloat16) -> np.ndarray:
+    """Dequantize one streamed KV chunk. Returns a qt.shape array."""
+    return dequantize_chunks([qt], interpret=interpret,
+                             out_dtype=out_dtype)[0]

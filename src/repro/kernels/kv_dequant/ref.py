@@ -5,26 +5,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
-def kv_dequant_ref(codes, scales, zeros, *, group: int,
-                   out_dtype=jnp.bfloat16):
-    """codes: (n, g*group) uint8 laid out as g groups of `group` values per
-    row; scales/zeros: (n, g) float32. Returns (n, g*group) out_dtype."""
-    n, width = codes.shape
-    g = width // group
-    c = codes.astype(jnp.float32).reshape(n, g, group)
-    x = c * scales[..., None] + zeros[..., None]
-    return x.reshape(n, width).astype(out_dtype)
-
-
-def kv_dequant_mixed_ref(codes, spans, zeros, bits, *, group: int,
-                         out_dtype=jnp.bfloat16):
-    """Mixed-bitwidth oracle: per-row `bits` (n, 1) int32 selects the
-    scale interpretation spans / (2^bits - 1); otherwise identical to
-    kv_dequant_ref."""
-    n, width = codes.shape
-    g = width // group
-    c = codes.astype(jnp.float32).reshape(n, g, group)
-    q = ((1 << bits.astype(jnp.int32)) - 1).astype(jnp.float32)
-    step = spans / q
-    x = c * step[..., None] + zeros[..., None]
-    return x.reshape(n, width).astype(out_dtype)
+def kv_dequant_ref(codes, scales, zeros, *, out_dtype=jnp.bfloat16):
+    """codes: (n, group) uint8, one group per row; scales/zeros: (n, 1)
+    float32. Returns (n, group) out_dtype."""
+    x = codes.astype(jnp.float32) * scales + zeros
+    return x.astype(out_dtype)

@@ -177,8 +177,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):    # jax < 0.5 returns [dict]
-            ca = ca[0] if ca else {}
         hlo = compiled.as_text()
         coll = parse_collectives(hlo)
         flops = float(ca.get("flops", 0.0))

@@ -37,8 +37,7 @@ from repro.core import baselines as B
 from repro.core.chunks import Chunk
 from repro.core.costs import NETWORKS
 from repro.data.workloads import WorkloadChunks
-from repro.kernels.kv_dequant.ops import (dequantize_chunk,
-                                          dequantize_chunks_mixed)
+from repro.kernels.kv_dequant.ops import dequantize_chunks
 from repro.models.api import Model
 
 
@@ -63,13 +62,18 @@ class ServeResult:
     n_computed: int
     migrations: int
     wall_s: float
+    # host-clock phase times of this request, each ending once its device
+    # work is done (ttft_s above is the planner's virtual-clock estimate)
+    load_wall_s: float
+    decode_wall_s: float
 
 
 class SparKVServer:
     def __init__(self, model: Model, params, spcfg: SparKVConfig,
                  *, profile: str = "jetson-orin",
                  network: str = "campus-wifi", capacity: int = 8,
-                 chunk_tokens: Optional[int] = None, seed: int = 0):
+                 chunk_tokens: Optional[int] = None, seed: int = 0,
+                 interpret: Optional[bool] = None):
         self.model = model
         self.params = params
         self.spcfg = spcfg
@@ -78,9 +82,13 @@ class SparKVServer:
         self.capacity = capacity
         self.chunk_tokens = chunk_tokens or spcfg.chunk_tokens
         self.seed = seed
+        # Pallas interpret mode of the dequant kernels; None lets
+        # repro.kernels.pallas_interpret decide by backend
+        self.interpret = interpret
         self.contexts: dict[int, StoredContext] = {}
         self.active_requests = 0
         self._next_id = 0
+        self._prefill = jax.jit(self.model.prefill)
         self._decode_step = jax.jit(self.model.decode_step,
                                     donate_argnums=(1,))
 
@@ -105,8 +113,8 @@ class SparKVServer:
         s = tokens.shape[1]
         ct = self.chunk_tokens
         assert s % ct == 0, f"context length must be a multiple of {ct}"
-        _, cache = self.model.prefill(self.params,
-                                      {"tokens": jnp.asarray(tokens)})
+        _, cache = self._prefill(self.params,
+                                 {"tokens": jnp.asarray(tokens)})
         k = np.asarray(cache["k"], np.float32)      # (L, 1, S, hkv, hd)
         v = np.asarray(cache["v"], np.float32)
         n_t, n_l = s // ct, cfg.num_layers
@@ -220,21 +228,12 @@ class SparKVServer:
             qk2 = dataclasses.replace(qk, codes=dk.astype(np.uint8))
             qv2 = dataclasses.replace(qv, codes=dv.astype(np.uint8))
             decoded.append((c, qk2, qv2))
-        if len({q.bits for _, qk2, qv2 in decoded
-                for q in (qk2, qv2)}) > 1:
-            # per-chunk adaptive widths: one mixed-bitwidth launch over
-            # every streamed chunk (exact-parity-tested against the
-            # per-chunk path, so policy never changes the assembled KV)
-            outs = dequantize_chunks_mixed(
+        if decoded:
+            # one launch over every streamed plane, whatever its width
+            outs = dequantize_chunks(
                 [q for _, qk2, qv2 in decoded for q in (qk2, qv2)],
-                out_dtype=jnp.float32)
+                interpret=self.interpret, out_dtype=jnp.float32)
             for (c, _, _), kd, vd in zip(decoded, outs[0::2], outs[1::2]):
-                k[c.l, 0, c.t * ct:(c.t + 1) * ct] = np.asarray(kd)
-                v[c.l, 0, c.t * ct:(c.t + 1) * ct] = np.asarray(vd)
-        else:
-            for c, qk2, qv2 in decoded:
-                kd = np.asarray(dequantize_chunk(qk2, out_dtype=jnp.float32))
-                vd = np.asarray(dequantize_chunk(qv2, out_dtype=jnp.float32))
                 k[c.l, 0, c.t * ct:(c.t + 1) * ct] = kd
                 v[c.l, 0, c.t * ct:(c.t + 1) * ct] = vd
         cache = {"k": jnp.asarray(k, jnp.bfloat16),
@@ -246,12 +245,16 @@ class SparKVServer:
                  seed: Optional[int] = None) -> ServeResult:
         """Serve one request: load context via `policy`, feed the prompt,
         decode max_new tokens greedily; quality vs the exact cache."""
-        t_wall = time.time()
+        t_wall = time.perf_counter()
         self.active_requests += 1
         try:
             st = self.contexts[cid]
             cache, res = self.load_context(cid, policy=policy, seed=seed)
+            jax.block_until_ready(cache)
+            t_loaded = time.perf_counter()
+            # _decode ends in a host read of the last logits
             toks, logits_seq = self._decode(st, cache, prompt, max_new)
+            t_decoded = time.perf_counter()
             if compare_exact:
                 exact_cache = {"k": jnp.asarray(st.exact_k, jnp.bfloat16),
                                "v": jnp.asarray(st.exact_v, jnp.bfloat16)}
@@ -268,7 +271,9 @@ class SparKVServer:
                 top1_agreement=agree, mean_kl=kl,
                 n_streamed=eng.n_streamed, n_computed=eng.n_computed,
                 migrations=getattr(eng, "n_migrations", 0),
-                wall_s=time.time() - t_wall)
+                wall_s=time.perf_counter() - t_wall,
+                load_wall_s=t_loaded - t_wall,
+                decode_wall_s=t_decoded - t_loaded)
         finally:
             self.active_requests -= 1
 

@@ -118,15 +118,17 @@ def test_quantize_tail_group_regression(rng):
 
 
 def test_quantize_spans_field(rng):
-    """spans is the bit-width-independent value range: scales must equal
-    spans / (2^bits - 1) bitwise (same fp32 division the mixed kernel
-    performs on-device)."""
+    """The per-group affine parameters the dequant kernel reads: scales
+    are the group's value span (hi - lo) / (2^bits - 1) in fp32, computed
+    on the host, and zeros the group's minimum."""
     for bits in (3, 5, 8):
         x = rng.normal(size=500).astype(np.float32)
         qt = quantize(x, bits, 64)
-        assert qt.spans is not None and qt.spans.dtype == np.float32
-        re = (qt.spans / np.float32((1 << bits) - 1)).astype(np.float32)
-        assert np.array_equal(re, qt.scales)
+        g = np.pad(x, (0, (-x.size) % 64), mode="edge").reshape(-1, 64)
+        span = np.maximum(g.max(1) - g.min(1), np.float32(1e-8))
+        assert qt.scales.dtype == np.float32
+        assert np.array_equal(qt.scales, span / np.float32((1 << bits) - 1))
+        assert np.array_equal(qt.zeros, g.min(1))
 
 
 def test_allocation_schedules(rng):

@@ -13,10 +13,9 @@ from repro.kernels.block_sparse_attn.kernel import block_sparse_attention
 from repro.kernels.block_sparse_attn.ref import block_sparse_attention_ref
 from repro.kernels.decode_attn.kernel import decode_attention
 from repro.kernels.decode_attn.ref import decode_attention_ref
-from repro.kernels.kv_dequant.kernel import kv_dequant, kv_dequant_mixed
-from repro.kernels.kv_dequant.ref import kv_dequant_mixed_ref, kv_dequant_ref
-from repro.kernels.kv_dequant.ops import (dequantize_chunk,
-                                          dequantize_chunks_mixed)
+from repro.kernels.kv_dequant.kernel import kv_dequant
+from repro.kernels.kv_dequant.ref import kv_dequant_ref
+from repro.kernels.kv_dequant.ops import dequantize_chunk, dequantize_chunks
 from repro.sparse.mask import block_scores, select_blocks
 
 KEYS = jax.random.split(jax.random.PRNGKey(7), 8)
@@ -97,130 +96,145 @@ def test_decode_attention_vs_ref(b, hq, hkv, skv, d, klen, blk):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def _group_rows(n, width, group):
+    """(n, width) row blocks of width // group groups each, laid out as
+    the kernel takes them: one group per row."""
+    return n * (width // group), group
+
+
 @pytest.mark.parametrize("n,width,group,bits", [
     (64, 128, 64, 5), (100, 512, 64, 4), (7, 256, 128, 8), (1024, 128, 32, 3),
 ])
 def test_kv_dequant_vs_ref(n, width, group, bits, rng):
-    codes = rng.integers(0, 1 << bits, size=(n, width)).astype(np.uint8)
-    g = width // group
-    scales = rng.uniform(0.01, 0.2, (n, g)).astype(np.float32)
-    zeros = rng.normal(size=(n, g)).astype(np.float32)
+    rows, group = _group_rows(n, width, group)
+    codes = rng.integers(0, 1 << bits, size=(rows, group)).astype(np.uint8)
+    scales = rng.uniform(0.01, 0.2, (rows, 1)).astype(np.float32)
+    zeros = rng.normal(size=(rows, 1)).astype(np.float32)
     out = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
-                     jnp.asarray(zeros), group=group, interpret=True,
+                     jnp.asarray(zeros), interpret=True,
                      out_dtype=jnp.float32)
     ref = kv_dequant_ref(jnp.asarray(codes), jnp.asarray(scales),
-                         jnp.asarray(zeros), group=group,
-                         out_dtype=jnp.float32)
+                         jnp.asarray(zeros), out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+    host = codes.astype(np.float32) * scales + zeros
+    np.testing.assert_allclose(np.asarray(out), host, atol=1e-6)
 
 
-@pytest.mark.parametrize("n,width,group,rows_blk", [
-    (37, 128, 64, 16),    # 37 % 16 = 5: ragged final grid block
-    (255, 256, 64, 64),   # 255 % 64 = 63
-    (129, 128, 32, 128),  # one full block + a single ragged row
-    (5, 192, 64, 8),      # n < rows_blk entirely (rows_blk clamped)
+@pytest.mark.parametrize("rows,group,rows_blk", [
+    (74, 64, 16),      # 74 % 16 = 10: ragged final grid block
+    (1020, 64, 64),    # 1020 % 64 = 60
+    (513, 32, 128),    # four full blocks + a single ragged row
+    (15, 64, 32),      # n < rows_blk entirely (rows_blk clamped)
 ])
-def test_kv_dequant_ragged_grid(n, width, group, rows_blk, rng):
+def test_kv_dequant_ragged_grid(rows, group, rows_blk, rng):
     """n % rows_blk != 0: the final grid block is ragged; the kernel must
     still match the oracle exactly on every row."""
     bits = 5
-    codes = rng.integers(0, 1 << bits, size=(n, width)).astype(np.uint8)
-    g = width // group
-    scales = rng.uniform(0.01, 0.2, (n, g)).astype(np.float32)
-    zeros = rng.normal(size=(n, g)).astype(np.float32)
+    codes = rng.integers(0, 1 << bits, size=(rows, group)).astype(np.uint8)
+    scales = rng.uniform(0.01, 0.2, (rows, 1)).astype(np.float32)
+    zeros = rng.normal(size=(rows, 1)).astype(np.float32)
     out = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
-                     jnp.asarray(zeros), group=group, rows_blk=rows_blk,
+                     jnp.asarray(zeros), rows_blk=rows_blk,
                      interpret=True, out_dtype=jnp.float32)
     ref = kv_dequant_ref(jnp.asarray(codes), jnp.asarray(scales),
-                         jnp.asarray(zeros), group=group,
-                         out_dtype=jnp.float32)
+                         jnp.asarray(zeros), out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
     # ragged tiling must be exactly the single-block launch (same kernel,
     # no padding leakage into valid rows)
     whole = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
-                       jnp.asarray(zeros), group=group, rows_blk=n,
+                       jnp.asarray(zeros), rows_blk=rows,
                        interpret=True, out_dtype=jnp.float32)
     assert np.array_equal(np.asarray(out), np.asarray(whole))
+
+
+def _mixed_width_rows(rows, group, rng):
+    """Rows of heterogeneous widths as quantize() would store them: each
+    row's step is its span / (2^bits - 1), divided on the host."""
+    bits = rng.choice([3, 4, 5, 6, 8], size=(rows, 1))
+    codes = (rng.integers(0, 256, size=(rows, group)) %
+             (1 << bits)).astype(np.uint8)
+    spans = rng.uniform(0.1, 4.0, (rows, 1)).astype(np.float32)
+    scales = (spans / ((1 << bits) - 1).astype(np.float32))
+    zeros = rng.normal(size=(rows, 1)).astype(np.float32)
+    return bits, codes, scales.astype(np.float32), zeros
 
 
 @pytest.mark.parametrize("n,width,group,rows_blk", [
     (64, 128, 64, 32), (53, 256, 64, 16), (7, 128, 32, 256),
 ])
 def test_kv_dequant_mixed_vs_ref(n, width, group, rows_blk, rng):
-    """Mixed-bitwidth kernel vs numpy/jnp oracle: heterogeneous per-row
-    widths, exact equality in fp32 (ragged grids included)."""
-    g = width // group
-    bits = rng.choice([3, 4, 5, 6, 8], size=(n, 1)).astype(np.int32)
-    codes = (rng.integers(0, 256, size=(n, width)) %
-             (1 << bits)).astype(np.uint8)
-    spans = rng.uniform(0.1, 4.0, (n, g)).astype(np.float32)
-    zeros = rng.normal(size=(n, g)).astype(np.float32)
-    out = kv_dequant_mixed(jnp.asarray(codes), jnp.asarray(spans),
-                           jnp.asarray(zeros), jnp.asarray(bits),
-                           group=group, rows_blk=rows_blk, interpret=True,
-                           out_dtype=jnp.float32)
-    ref = kv_dequant_mixed_ref(jnp.asarray(codes), jnp.asarray(spans),
-                               jnp.asarray(zeros), jnp.asarray(bits),
-                               group=group, out_dtype=jnp.float32)
+    """One launch over rows of heterogeneous bit widths vs the jnp and
+    numpy oracles, exact in fp32 (ragged grids included)."""
+    rows, group = _group_rows(n, width, group)
+    _, codes, scales, zeros = _mixed_width_rows(rows, group, rng)
+    out = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
+                     jnp.asarray(zeros), rows_blk=rows_blk, interpret=True,
+                     out_dtype=jnp.float32)
+    ref = kv_dequant_ref(jnp.asarray(codes), jnp.asarray(scales),
+                         jnp.asarray(zeros), out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
-    whole = kv_dequant_mixed(jnp.asarray(codes), jnp.asarray(spans),
-                             jnp.asarray(zeros), jnp.asarray(bits),
-                             group=group, rows_blk=n, interpret=True,
-                             out_dtype=jnp.float32)
+    host = codes.astype(np.float32) * scales + zeros
+    np.testing.assert_allclose(np.asarray(out), host, atol=1e-6)
+    whole = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
+                       jnp.asarray(zeros), rows_blk=rows, interpret=True,
+                       out_dtype=jnp.float32)
     assert np.array_equal(np.asarray(out), np.asarray(whole))
 
 
 def test_kv_dequant_mixed_uniform_bits_parity(rng):
-    """A uniform-bits mixed launch is BIT-IDENTICAL to the single-bits
-    kernel fed the host-computed scales: the kernel's span / (2^b - 1)
-    is the same IEEE fp32 division quantize() performed on the host."""
-    n, width, group, b = 96, 256, 64, 5
-    g = width // group
-    codes = rng.integers(0, 1 << b, size=(n, width)).astype(np.uint8)
-    spans = rng.uniform(0.1, 4.0, (n, g)).astype(np.float32)
-    zeros = rng.normal(size=(n, g)).astype(np.float32)
-    scales = (spans / np.float32((1 << b) - 1)).astype(np.float32)
-    bits = np.full((n, 1), b, np.int32)
-    mixed = kv_dequant_mixed(jnp.asarray(codes), jnp.asarray(spans),
-                             jnp.asarray(zeros), jnp.asarray(bits),
-                             group=group, interpret=True,
-                             out_dtype=jnp.float32)
-    single = kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
-                        jnp.asarray(zeros), group=group, interpret=True,
-                        out_dtype=jnp.float32)
-    assert np.array_equal(np.asarray(mixed), np.asarray(single))
+    """A launch over rows of mixed widths is BIT-IDENTICAL, row for row,
+    to one launch per width: rows never interact, so a chunk assembles
+    the same whichever chunks share its launch."""
+    rows, group = _group_rows(96, 256, 64)
+    bits, codes, scales, zeros = _mixed_width_rows(rows, group, rng)
+    mixed = np.asarray(kv_dequant(jnp.asarray(codes), jnp.asarray(scales),
+                                  jnp.asarray(zeros), interpret=True,
+                                  out_dtype=jnp.float32))
+    for b in np.unique(bits):
+        sel = bits[:, 0] == b
+        single = kv_dequant(jnp.asarray(codes[sel]),
+                            jnp.asarray(scales[sel]),
+                            jnp.asarray(zeros[sel]), interpret=True,
+                            out_dtype=jnp.float32)
+        assert np.array_equal(mixed[sel], np.asarray(single))
 
 
 def test_dequantize_chunks_mixed_parity(rng):
-    """One mixed launch over chunks of heterogeneous widths returns, per
-    chunk, exactly the per-chunk single-bits launch (fp32) and stays
-    within rtol 1e-5 of the host dequantize at bf16."""
+    """One launch over chunks of heterogeneous widths returns, per
+    chunk, exactly the per-chunk launch (fp32) and stays within rtol
+    1e-5 of the host dequantize at bf16."""
     shapes = [(64, 48), (7, 33), (128, 64), (19, 5)]
     widths = [8, 3, 5, 4]
     qts = [quantize(rng.normal(size=s).astype(np.float32), b, 64)
            for s, b in zip(shapes, widths)]
-    mixed = dequantize_chunks_mixed(qts, out_dtype=jnp.float32)
+    mixed = dequantize_chunks(qts, interpret=True, out_dtype=jnp.float32)
     for qt, m in zip(qts, mixed):
-        single = np.asarray(dequantize_chunk(qt, out_dtype=jnp.float32))
+        single = np.asarray(dequantize_chunk(qt, interpret=True,
+                                             out_dtype=jnp.float32))
         assert np.array_equal(np.asarray(m), single)
         np.testing.assert_allclose(np.asarray(m), dequantize(qt),
                                    atol=1e-5)
-    mixed_bf = dequantize_chunks_mixed(qts, out_dtype=jnp.bfloat16)
+    mixed_bf = dequantize_chunks(qts, interpret=True,
+                                 out_dtype=jnp.bfloat16)
     for qt, m in zip(qts, mixed_bf):
         np.testing.assert_allclose(np.asarray(m, np.float32),
                                    dequantize(qt), rtol=1e-5,
                                    atol=qt.scales.max() * 0.02 + 1e-2)
 
 
-def test_dequantize_chunks_mixed_legacy_spans(rng):
-    """Pre-spans QuantizedTensors (spans=None) still go through the
-    mixed path via reconstruction from scales."""
-    import dataclasses
-    qt = quantize(rng.normal(size=(32, 32)).astype(np.float32), 4, 64)
-    legacy = dataclasses.replace(qt, spans=None)
-    (m,) = dequantize_chunks_mixed([legacy], out_dtype=jnp.float32)
-    single = np.asarray(dequantize_chunk(qt, out_dtype=jnp.float32))
-    assert np.array_equal(np.asarray(m), single)
+def test_dequantize_chunks_ragged_tail_isolated(rng):
+    """A chunk whose size is not a multiple of the group pads its tail
+    group with zero codes; the padding never reaches the next chunk of
+    the launch, and the outputs keep each chunk's own shape."""
+    a = quantize(rng.normal(size=(5, 7)).astype(np.float32), 3, 64)
+    b = quantize(rng.normal(size=(32, 32)).astype(np.float32), 8, 64)
+    ma, mb = dequantize_chunks([a, b], interpret=True,
+                               out_dtype=jnp.float32)
+    (only_b,) = dequantize_chunks([b], interpret=True,
+                                  out_dtype=jnp.float32)
+    assert ma.shape == (5, 7) and mb.shape == (32, 32)
+    assert np.array_equal(mb, only_b)
+    np.testing.assert_allclose(ma, dequantize(a), atol=1e-5)
 
 
 @settings(max_examples=15, deadline=None, derandomize=True)
@@ -230,7 +244,8 @@ def test_dequant_roundtrip_hypothesis(n_vals, bits, group):
     x = rng.normal(size=n_vals).astype(np.float32)
     qt = quantize(x, bits, group)
     host = dequantize(qt)
-    dev = np.asarray(dequantize_chunk(qt, out_dtype=jnp.float32))
+    dev = np.asarray(dequantize_chunk(qt, interpret=True,
+                                      out_dtype=jnp.float32))
     np.testing.assert_allclose(dev, host, atol=1e-5)
     # quantization error bounded by half a step per group
     assert np.abs(host - x).max() <= qt.scales.max() * 0.51 + 1e-6

@@ -325,15 +325,16 @@ def test_bits_ladder_roundtrip_and_kernel():
     for bits in BITRATE_LEVELS:
         qt = quantize(kv, bits, group)
         errs.append(np.sqrt(np.mean((dequantize(qt) - kv) ** 2)))
-        codes = qt.codes.reshape(rows, width)
-        scales = qt.scales.reshape(rows, width // group)
-        zeros = qt.zeros.reshape(rows, width // group)
-        out = np.asarray(kv_dequant(codes, scales, zeros, group=group,
-                                    interpret=True), np.float32)
+        # the kernel's layout: one quantization group per row
+        codes = qt.codes.reshape(-1, group)
+        scales = qt.scales[:, None]
+        zeros = qt.zeros[:, None]
+        out = np.asarray(kv_dequant(codes, scales, zeros, interpret=True),
+                         np.float32).reshape(rows, width)
         ref = np.asarray(kv_dequant_ref(jnp.asarray(codes),
                                         jnp.asarray(scales),
-                                        jnp.asarray(zeros), group=group),
-                         np.float32)
+                                        jnp.asarray(zeros)),
+                         np.float32).reshape(rows, width)
         # one bf16 ulp: interpret-mode rounding at the cast boundary
         np.testing.assert_allclose(out, ref, rtol=2 ** -7, atol=1e-6)
         np.testing.assert_allclose(out, dequantize(qt).reshape(rows, width),
