@@ -1,0 +1,9 @@
+"""CPU tests of the chip benchmark: the harness's modules at reduced
+sizes, with the Pallas kernels in interpret mode."""
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
