@@ -29,9 +29,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.compression import huffman
-from repro.compression.quantize import quantize
+from repro.compression.quantize import QuantizedTensor, quantize
 from repro.configs.base import SparKVConfig
 from repro.core import baselines as B
 from repro.core.chunks import Chunk
@@ -60,12 +61,13 @@ class ServeResult:
     mean_kl: float
     n_streamed: int
     n_computed: int
-    migrations: int
     wall_s: float
     # host-clock phase times of this request, each ending once its device
     # work is done (ttft_s above is the planner's virtual-clock estimate)
     load_wall_s: float
     decode_wall_s: float
+    # generate() start -> the first answer token's argmax on the host
+    first_token_s: float
 
 
 class SparKVServer:
@@ -88,6 +90,8 @@ class SparKVServer:
         self.contexts: dict[int, StoredContext] = {}
         self.active_requests = 0
         self._next_id = 0
+        self._n_requests = 0          # serial of generate() calls
+        self._first_token_at = None   # host clock, set by _decode
         self._prefill = jax.jit(self.model.prefill)
         self._decode_step = jax.jit(self.model.decode_step,
                                     donate_argnums=(1,))
@@ -205,39 +209,55 @@ class SparKVServer:
     # ---------------- edge side ----------------
     def load_context(self, cid: int, *, policy: str = "sparkv",
                      util: Optional[float] = None, seed: Optional[int] = None):
-        """Run the loading pipeline; returns (cache jnp, PipelineResult)."""
+        """Run the loading pipeline; returns (cache jnp, PipelineResult),
+        the cache on the device."""
         st = self.contexts[cid]
         cfg = self.model.cfg
         spcfg = self.spcfg
         u = self.utilization() if util is None else util
         net = NETWORKS[self.network]
-        res = B.PIPELINES[policy](cfg, st.wl, self.profile, net, spcfg,
-                                  util=u, seed=seed or self.seed)
-        eng = res.engine
+        with TraceAnnotation("sparkv.load.plan") as span:
+            res = B.PIPELINES[policy](cfg, st.wl, self.profile, net, spcfg,
+                                      util=u, seed=seed or self.seed)
+            eng = res.engine
+            span.set_metadata(streamed=eng.n_streamed,
+                              computed=eng.n_computed,
+                              migrations=getattr(eng, "n_migrations", 0))
         # concrete assembly
-        k = st.exact_k.copy()
-        v = st.exact_v.copy()
+        with TraceAnnotation("sparkv.load.copy_exact",
+                             nbytes=st.exact_k.nbytes + st.exact_v.nbytes):
+            k = st.exact_k.copy()
+            v = st.exact_v.copy()
         ct = self.chunk_tokens
         streamed = sorted(getattr(eng, "streamed_set", set()))
         decoded = []
         for c in streamed:
             ek, ev, qk, qv = st.encoded[c]
-            dk = huffman.decode(ek)
-            dv = huffman.decode(ev)
-            assert np.array_equal(dk, qk.codes), "bitstream corruption"
-            qk2 = dataclasses.replace(qk, codes=dk.astype(np.uint8))
-            qv2 = dataclasses.replace(qv, codes=dv.astype(np.uint8))
-            decoded.append((c, qk2, qv2))
+            decoded.append((c, _entropy_decode(ek, qk),
+                            _entropy_decode(ev, qv)))
         if decoded:
             # one launch over every streamed plane, whatever its width
-            outs = dequantize_chunks(
-                [q for _, qk2, qv2 in decoded for q in (qk2, qv2)],
-                interpret=self.interpret, out_dtype=jnp.float32)
-            for (c, _, _), kd, vd in zip(decoded, outs[0::2], outs[1::2]):
-                k[c.l, 0, c.t * ct:(c.t + 1) * ct] = kd
-                v[c.l, 0, c.t * ct:(c.t + 1) * ct] = vd
-        cache = {"k": jnp.asarray(k, jnp.bfloat16),
-                 "v": jnp.asarray(v, jnp.bfloat16)}
+            qts = [q for _, qk2, qv2 in decoded for q in (qk2, qv2)]
+            rows = sum(q.scales.shape[0] for q in qts)
+            group = qts[0].group
+            # up: uint8 codes and a float32 scale and zero per group row;
+            # down: float32 values
+            with TraceAnnotation("sparkv.load.dequant",
+                                 h2d_bytes=rows * (group + 8),
+                                 d2h_bytes=rows * group * 4):
+                outs = dequantize_chunks(qts, interpret=self.interpret,
+                                         out_dtype=jnp.float32)
+            with TraceAnnotation("sparkv.load.scatter"):
+                for (c, _, _), kd, vd in zip(decoded, outs[0::2],
+                                             outs[1::2]):
+                    k[c.l, 0, c.t * ct:(c.t + 1) * ct] = kd
+                    v[c.l, 0, c.t * ct:(c.t + 1) * ct] = vd
+        # the host casts to bfloat16, then uploads
+        with TraceAnnotation("sparkv.load.upload",
+                             h2d_bytes=(k.size + v.size) * 2):
+            cache = {"k": jnp.asarray(k, jnp.bfloat16),
+                     "v": jnp.asarray(v, jnp.bfloat16)}
+            jax.block_until_ready(cache)
         return cache, res
 
     def generate(self, cid: int, prompt: np.ndarray, max_new: int = 8,
@@ -247,33 +267,41 @@ class SparKVServer:
         decode max_new tokens greedily; quality vs the exact cache."""
         t_wall = time.perf_counter()
         self.active_requests += 1
+        self._n_requests += 1
         try:
-            st = self.contexts[cid]
-            cache, res = self.load_context(cid, policy=policy, seed=seed)
-            jax.block_until_ready(cache)
-            t_loaded = time.perf_counter()
-            # _decode ends in a host read of the last logits
-            toks, logits_seq = self._decode(st, cache, prompt, max_new)
-            t_decoded = time.perf_counter()
-            if compare_exact:
-                exact_cache = {"k": jnp.asarray(st.exact_k, jnp.bfloat16),
-                               "v": jnp.asarray(st.exact_v, jnp.bfloat16)}
-                etoks, elogits = self._decode(st, exact_cache, prompt,
-                                              max_new)
-                agree = float(np.mean(toks == etoks))
-                kl = float(np.mean([_kl(e, a) for e, a
-                                    in zip(elogits, logits_seq)]))
-            else:
-                agree, kl = 1.0, 0.0
-            eng = res.engine
-            return ServeResult(
-                ttft_s=res.ttft_s, energy_j=res.energy_j, tokens=toks,
-                top1_agreement=agree, mean_kl=kl,
-                n_streamed=eng.n_streamed, n_computed=eng.n_computed,
-                migrations=getattr(eng, "n_migrations", 0),
-                wall_s=time.perf_counter() - t_wall,
-                load_wall_s=t_loaded - t_wall,
-                decode_wall_s=t_decoded - t_loaded)
+            with TraceAnnotation("sparkv.request", request=self._n_requests,
+                                 policy=policy):
+                st = self.contexts[cid]
+                with TraceAnnotation("sparkv.load"):
+                    t_load = time.perf_counter()
+                    cache, res = self.load_context(cid, policy=policy,
+                                                   seed=seed)
+                    t_loaded = time.perf_counter()
+                # _decode ends in a host read of the last logits
+                toks, logits_seq = self._decode(st, cache, prompt, max_new)
+                t_decoded = time.perf_counter()
+                t_first = self._first_token_at
+                if compare_exact:
+                    with TraceAnnotation("sparkv.compare_exact"):
+                        exact_cache = {
+                            "k": jnp.asarray(st.exact_k, jnp.bfloat16),
+                            "v": jnp.asarray(st.exact_v, jnp.bfloat16)}
+                        etoks, elogits = self._decode(st, exact_cache,
+                                                      prompt, max_new)
+                    agree = float(np.mean(toks == etoks))
+                    kl = float(np.mean([_kl(e, a) for e, a
+                                        in zip(elogits, logits_seq)]))
+                else:
+                    agree, kl = 1.0, 0.0
+                eng = res.engine
+                return ServeResult(
+                    ttft_s=res.ttft_s, energy_j=res.energy_j, tokens=toks,
+                    top1_agreement=agree, mean_kl=kl,
+                    n_streamed=eng.n_streamed, n_computed=eng.n_computed,
+                    wall_s=time.perf_counter() - t_wall,
+                    load_wall_s=t_loaded - t_load,
+                    decode_wall_s=t_decoded - t_loaded,
+                    first_token_s=t_first - t_wall)
         finally:
             self.active_requests -= 1
 
@@ -323,31 +351,53 @@ class SparKVServer:
         return cluster.run(specs)
 
     def _decode(self, st: StoredContext, cache, prompt, max_new):
+        """Feed the prompt, then decode max_new tokens greedily; returns
+        the answer tokens and their logits. Sets ``_first_token_at``, the
+        host clock once the first answer token's argmax has returned."""
         cfg = self.model.cfg
         s = st.tokens.shape[1]
-        # context cache is exactly s (read-only); prompt + generated
-        # tokens go to the replicated decode tail buffer
-        full = self.model.init_cache(1, s)
-        full["k"] = cache["k"][:, :, :s].astype(full["k"].dtype)
-        full["v"] = cache["v"][:, :, :s].astype(full["v"].dtype)
-        toks = []
-        logits_list = []
-        cur = None
-        pos = s
-        feed = list(prompt) + [None] * max_new
-        for tok in feed:
-            if tok is None:
-                tok = cur
-            logits, full = self._decode_step(
-                self.params, full, jnp.asarray([tok], jnp.int32),
-                jnp.int32(pos))
-            pos += 1
-            lf = np.asarray(logits[0], np.float32)
-            cur = int(lf[:cfg.vocab_size].argmax())
-            toks.append(cur)
-            logits_list.append(lf)
-        return np.asarray(toks[len(prompt):]), \
-            logits_list[len(prompt):]
+        with TraceAnnotation("sparkv.decode"):
+            # context cache is exactly s (read-only); prompt + generated
+            # tokens go to the replicated decode tail buffer
+            with TraceAnnotation("sparkv.decode.init_cache"):
+                full = self.model.init_cache(1, s)
+                full["k"] = cache["k"][:, :, :s].astype(full["k"].dtype)
+                full["v"] = cache["v"][:, :, :s].astype(full["v"].dtype)
+            toks = []
+            logits_list = []
+            cur = None
+            pos = s
+            n_q = len(prompt)
+            feed = list(prompt) + [None] * max_new
+            for i, tok in enumerate(feed):
+                if tok is None:
+                    tok = cur
+                kind = "question" if i < n_q else "answer"
+                with TraceAnnotation(f"sparkv.step.{kind}", pos=pos) as span:
+                    logits, full = self._decode_step(
+                        self.params, full, jnp.asarray([tok], jnp.int32),
+                        jnp.int32(pos))
+                    row = logits[0]
+                    lf = np.asarray(row, np.float32)
+                    cur = int(lf[:cfg.vocab_size].argmax())
+                    span.set_metadata(d2h_bytes=row.nbytes)
+                if i == n_q - 1:
+                    self._first_token_at = time.perf_counter()
+                pos += 1
+                toks.append(cur)
+                logits_list.append(lf)
+        return np.asarray(toks[n_q:]), logits_list[n_q:]
+
+
+def _entropy_decode(enc: huffman.EncodedChunk,
+                    qt: QuantizedTensor) -> QuantizedTensor:
+    """One streamed plane's codes, Huffman-decoded from its bitstream and
+    checked against the codes it was encoded from."""
+    with TraceAnnotation("sparkv.load.entropy_decode", values=enc.n_total,
+                         nbytes=enc.payload_bytes()):
+        codes = huffman.decode(enc)
+        assert np.array_equal(codes, qt.codes), "bitstream corruption"
+    return dataclasses.replace(qt, codes=codes.astype(np.uint8))
 
 
 def _kl(p_logits: np.ndarray, q_logits: np.ndarray) -> float:
