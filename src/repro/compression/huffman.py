@@ -3,13 +3,14 @@
 Real bitstreams (this is what goes over the simulated wire, and roundtrip
 exactness is tested). Sequential Huffman decode is unvectorizable, so —
 like production entropy coders (interleaved rANS) — we split symbols into S
-independent streams decoded in lockstep with numpy gathers: the decode loop
-runs max-symbols-per-stream iterations, each vectorized across streams.
+independent streams. `decode_many` decodes every stream of several
+encoded planes in lockstep with numpy gathers: the loop runs
+max-symbols-per-stream passes, each vectorized across all planes' streams.
 
-Max code length is capped at MAX_LEN (table-driven decode, 2^16 entries);
-if the unrestricted Huffman tree exceeds it, counts are flattened toward
-uniform until it fits (tiny rate loss, recorded by the caller via actual
-encoded size).
+Max code length is capped at MAX_LEN (table-driven decode, at most 2^16
+entries); if the unrestricted Huffman tree exceeds it, counts are
+flattened toward uniform until it fits (tiny rate loss, recorded by the
+caller via actual encoded size).
 """
 from __future__ import annotations
 
@@ -84,16 +85,17 @@ class HuffmanCode:
     def table_bytes(self) -> int:
         return len(self.lengths)  # one length byte per symbol (canonical)
 
-    def decode_table(self):
-        """(symbol, length) uint16 arrays indexed by 16-bit window."""
-        sym = np.zeros(1 << MAX_LEN, np.uint16)
-        ln = np.zeros(1 << MAX_LEN, np.uint16)
+    def decode_table(self, bits: int = MAX_LEN):
+        """(symbol, length) uint16 arrays indexed by the next ``bits`` bits
+        of the stream; ``bits`` is at least the longest code length."""
+        sym = np.zeros(1 << bits, np.uint16)
+        ln = np.zeros(1 << bits, np.uint16)
         for s, l in enumerate(self.lengths):
             l = int(l)
             if l == 0:
                 continue
-            prefix = int(self.codes[s]) << (MAX_LEN - l)
-            span = 1 << (MAX_LEN - l)
+            prefix = int(self.codes[s]) << (bits - l)
+            span = 1 << (bits - l)
             sym[prefix:prefix + span] = s
             ln[prefix:prefix + span] = l
         return sym, ln
@@ -168,32 +170,103 @@ def encode(symbols: np.ndarray, n_alphabet: int,
 
 
 def decode(enc: EncodedChunk) -> np.ndarray:
-    sym_t, len_t = enc.code.decode_table()
-    s, nbytes = enc.streams.shape
-    per = int(enc.n_per_stream.max())
-    out = np.zeros((s, per), np.uint16)
-    pos = np.zeros(s, np.int64)
-    b = enc.streams.astype(np.uint32)
-    pad = np.zeros((s, 4), np.uint32)
-    b = np.concatenate([b, pad], axis=1)
-    rows = np.arange(s)
-    active_count = enc.n_per_stream.copy()
-    for i in range(per):
-        byte_idx = pos >> 3
-        shift = (pos & 7).astype(np.uint32)
-        w = ((b[rows, byte_idx] << 16)
-             | (b[rows, byte_idx + 1] << 8)
-             | b[rows, byte_idx + 2])
-        w = (w >> (8 - shift)) & 0xFFFF
-        sym = sym_t[w]
-        ln = len_t[w]
-        act = i < active_count
-        out[:, i] = np.where(act, sym, 0)
-        pos = pos + np.where(act, ln.astype(np.int64), 0)
-    flat = []
-    for r in range(s):
-        flat.append(out[r, :int(enc.n_per_stream[r])])
-    return np.concatenate(flat) if flat else np.zeros(0, np.uint16)
+    """One plane's symbols (uint16), as ``encode`` took them."""
+    return decode_many([enc])[0]
+
+
+def lockstep_shape(encs: list[EncodedChunk]) -> tuple[int, int, int]:
+    """(lanes, steps, table_bits) of decoding ``encs`` together: one lane
+    per stream, one pass per symbol of the longest stream, and tables
+    indexed by as many bits as the longest code."""
+    lanes = sum(e.streams.shape[0] for e in encs)
+    steps = max((int(e.n_per_stream.max(initial=0)) for e in encs),
+                default=0)
+    bits = max((int(e.code.lengths.max(initial=0)) for e in encs),
+               default=0)
+    return lanes, steps, bits
+
+
+_TILE = (64, 1024)      # (lanes, passes) per block of the final transpose
+
+
+def decode_many(encs: list[EncodedChunk]) -> list[np.ndarray]:
+    """Decode several encoded planes in one lockstep loop; returns each
+    plane's symbols (uint16) as ``encode`` took them.
+
+    Every stream of every plane is one lane. Each pass reads the next
+    ``bits`` bits of each lane that has symbols left and looks them up in
+    the lane's own plane's table, cut to ``2^bits`` entries. Lanes run
+    longest first, so those with symbols left are always a prefix, and a
+    lane that has decoded its count reads nothing more; the others read
+    only inside their own byte row."""
+    if not encs:
+        return []
+    lanes, steps, bits = lockstep_shape(encs)
+    # one int32 entry per window: the symbol, and the code length above it
+    table = np.concatenate([
+        sym.astype(np.int32) | (ln.astype(np.int32) << 16)
+        for sym, ln in (e.code.decode_table(bits) for e in encs)])
+    # one byte row per lane, padded to the longest plane plus 4 bytes of
+    # slack, then the 24-bit window that starts at each byte
+    width = max(e.streams.shape[1] for e in encs) + 4
+    rows = np.zeros((lanes, width), np.uint8)
+    plane = np.repeat(np.arange(len(encs)),
+                      [e.streams.shape[0] for e in encs])
+    lo = 0
+    for e in encs:
+        rows[lo:lo + e.streams.shape[0], :e.streams.shape[1]] = e.streams
+        lo += e.streams.shape[0]
+    win = rows[:, :-2].astype(np.int32)
+    win <<= 8
+    win |= rows[:, 1:-1]
+    win <<= 8
+    win |= rows[:, 2:]
+    win = win.reshape(-1)
+    # lane state, longest lane first: bit address in ``win``, table offset
+    counts = np.concatenate([e.n_per_stream for e in encs])
+    order = np.argsort(-counts, kind="stable")
+    ends = counts[order]
+    addr_t = np.int32 if win.size * 8 < 2 ** 31 else np.int64
+    pos = order.astype(addr_t) * ((width - 2) * 8)
+    off = (plane[order] << bits).astype(np.int32)
+    byte = np.empty(lanes, addr_t)
+    shift = np.empty(lanes, np.int32)
+    w = np.empty(lanes, np.int32)
+    out = np.empty((steps, lanes), np.uint16)
+    mask = (1 << bits) - 1
+    i = 0
+    while i < steps:
+        k = int(np.count_nonzero(ends > i))     # lanes with symbols left
+        stop = int(ends[k - 1])
+        p, b, sh, wk, o = pos[:k], byte[:k], shift[:k], w[:k], off[:k]
+        for j in range(i, stop):
+            np.right_shift(p, 3, out=b)
+            np.take(win, b, out=wk)
+            np.bitwise_and(p, 7, out=sh)
+            np.subtract(24 - bits, sh, out=sh)
+            np.right_shift(wk, sh, out=wk)
+            np.bitwise_and(wk, mask, out=wk)
+            np.add(wk, o, out=wk)
+            # take buffers ``out`` in its default mode, so it may alias
+            np.take(table, wk, out=wk)
+            out[j, :k] = wk                     # the symbol: low 16 bits
+            np.right_shift(wk, 16, out=wk)
+            np.add(p, wk, out=p)
+        i = stop
+    # lanes by rows, in blocks that stay in cache
+    by_lane = np.empty((lanes, steps), np.uint16)
+    tl, ts = _TILE
+    for a in range(0, lanes, tl):
+        for c in range(0, steps, ts):
+            by_lane[a:a + tl, c:c + ts] = out[c:c + ts, a:a + tl].T
+    row = np.empty(lanes, np.intp)
+    row[order] = np.arange(lanes)
+    res, lo = [], 0
+    for e in encs:
+        res.append(np.concatenate(
+            [by_lane[row[lo + r], :n] for r, n in enumerate(e.n_per_stream)]))
+        lo += e.streams.shape[0]
+    return res
 
 
 def entropy_bits(symbols: np.ndarray, n_alphabet: int) -> float:

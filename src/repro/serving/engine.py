@@ -230,14 +230,9 @@ class SparKVServer:
             v = st.exact_v.copy()
         ct = self.chunk_tokens
         streamed = sorted(getattr(eng, "streamed_set", set()))
-        decoded = []
-        for c in streamed:
-            ek, ev, qk, qv = st.encoded[c]
-            decoded.append((c, _entropy_decode(ek, qk),
-                            _entropy_decode(ev, qv)))
-        if decoded:
+        qts = _entropy_decode([st.encoded[c] for c in streamed])
+        if qts:
             # one launch over every streamed plane, whatever its width
-            qts = [q for _, qk2, qv2 in decoded for q in (qk2, qv2)]
             rows = sum(q.scales.shape[0] for q in qts)
             group = qts[0].group
             # up: uint8 codes and a float32 scale and zero per group row;
@@ -248,8 +243,7 @@ class SparKVServer:
                 outs = dequantize_chunks(qts, interpret=self.interpret,
                                          out_dtype=jnp.float32)
             with TraceAnnotation("sparkv.load.scatter"):
-                for (c, _, _), kd, vd in zip(decoded, outs[0::2],
-                                             outs[1::2]):
+                for c, kd, vd in zip(streamed, outs[0::2], outs[1::2]):
                     k[c.l, 0, c.t * ct:(c.t + 1) * ct] = kd
                     v[c.l, 0, c.t * ct:(c.t + 1) * ct] = vd
         # the host casts to bfloat16, then uploads
@@ -389,15 +383,25 @@ class SparKVServer:
         return np.asarray(toks[n_q:]), logits_list[n_q:]
 
 
-def _entropy_decode(enc: huffman.EncodedChunk,
-                    qt: QuantizedTensor) -> QuantizedTensor:
-    """One streamed plane's codes, Huffman-decoded from its bitstream and
-    checked against the codes it was encoded from."""
-    with TraceAnnotation("sparkv.load.entropy_decode", values=enc.n_total,
-                         nbytes=enc.payload_bytes()):
-        codes = huffman.decode(enc)
-        assert np.array_equal(codes, qt.codes), "bitstream corruption"
-    return dataclasses.replace(qt, codes=codes.astype(np.uint8))
+def _entropy_decode(stored: list) -> list[QuantizedTensor]:
+    """The K and V plane codes of each stored chunk in turn, Huffman-decoded
+    from their bitstreams in one lockstep loop, each checked against the
+    codes it was encoded from."""
+    if not stored:
+        return []
+    encs = [enc for ek, ev, _, _ in stored for enc in (ek, ev)]
+    qts = [qt for _, _, qk, qv in stored for qt in (qk, qv)]
+    lanes, steps, bits = huffman.lockstep_shape(encs)
+    with TraceAnnotation("sparkv.load.entropy_decode",
+                         values=sum(e.n_total for e in encs),
+                         nbytes=sum(e.payload_bytes() for e in encs),
+                         planes=len(encs), lanes=lanes, steps=steps,
+                         table_bits=bits):
+        codes = huffman.decode_many(encs)
+        for c, qt in zip(codes, qts):
+            assert np.array_equal(c, qt.codes), "bitstream corruption"
+    return [dataclasses.replace(qt, codes=c.astype(np.uint8))
+            for c, qt in zip(codes, qts)]
 
 
 def _kl(p_logits: np.ndarray, q_logits: np.ndarray) -> float:

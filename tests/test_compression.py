@@ -1,6 +1,6 @@
 """Huffman codec + quantization properties (hypothesis)."""
 import numpy as np
-import pytest  # noqa: F401
+import pytest
 # real hypothesis in CI; deterministic stub from tests/_vendor otherwise
 # (wired by conftest.py) — the suite never skips
 from hypothesis import given, settings, strategies as st
@@ -27,6 +27,82 @@ def test_huffman_roundtrip(n, bits, streams, skew):
     enc = H.encode(x, alpha, n_streams=streams)
     dec = H.decode(enc)
     assert np.array_equal(dec, x)
+
+
+def _skewed(n, bits, skew, seed):
+    """n symbols of a ``bits``-bit alphabet, peaked in the middle like
+    quantized KV."""
+    alpha = 1 << bits
+    p = np.exp(-skew * np.abs(np.arange(alpha) - alpha / 2) / alpha)
+    return np.random.default_rng(seed).choice(
+        alpha, size=n, p=p / p.sum()).astype(np.uint16)
+
+
+def _to_max_len(seed):
+    """Counts halving from symbol to symbol: the Huffman code of the
+    17 symbols reaches MAX_LEN (lengths 1, 2, ..., 16, 16)."""
+    counts = [1 << (16 - i) for i in range(16)] + [1]
+    x = np.repeat(np.arange(17, dtype=np.uint16), counts)
+    return np.random.default_rng(seed).permutation(x)
+
+
+# each case: planes decoded in one call, as (symbols, alphabet, streams)
+DECODE_MANY_CASES = {
+    "widths_3_5_8": lambda: [(_skewed(4096, 3, 1.0, 1), 8, 64),
+                             (_skewed(4096, 5, 2.0, 2), 32, 64),
+                             (_skewed(4096, 8, 4.0, 3), 256, 64)],
+    "lengths_and_short_last_stream": lambda: [
+        (_skewed(64 * 50 + 13, 5, 3.0, 4), 32, 64),
+        (_skewed(1000, 5, 2.0, 5), 32, 64),
+        (_skewed(70, 5, 1.0, 6), 32, 64),
+        (_skewed(5, 5, 1.0, 7), 32, 64),
+        (_skewed(20000, 5, 0.5, 8), 32, 7)],
+    "one_symbol_and_empty": lambda: [
+        (np.full(3000, 9, np.uint16), 32, 64),
+        (np.zeros(0, np.uint16), 32, 64),
+        (_skewed(3000, 4, 2.0, 9), 16, 64),
+        (np.full(1, 3, np.uint16), 8, 64)],
+    "codes_reach_max_len": lambda: [(_to_max_len(10), 256, 64),
+                                    (_skewed(5000, 5, 2.0, 11), 32, 64)],
+    "only_empty": lambda: [(np.zeros(0, np.uint16), 32, 64)] * 2,
+    "one_plane_one_stream": lambda: [(_skewed(777, 6, 2.0, 12), 64, 1)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_MANY_CASES))
+def test_huffman_decode_many_roundtrip(case):
+    planes = DECODE_MANY_CASES[case]()
+    encs = [H.encode(x, alpha, n_streams=s) for x, alpha, s in planes]
+    if case == "codes_reach_max_len":
+        assert int(encs[0].code.lengths.max()) == H.MAX_LEN
+    decoded = H.decode_many(encs)
+    assert len(decoded) == len(planes)
+    for (x, _, _), d, enc in zip(planes, decoded, encs):
+        assert d.dtype == np.uint16 and np.array_equal(d, x)
+        assert np.array_equal(H.decode(enc), x)
+    lanes, steps, bits = H.lockstep_shape(encs)
+    assert lanes == sum(e.streams.shape[0] for e in encs)
+    assert steps == max(int(e.n_per_stream.max()) for e in encs)
+    assert bits == max(int(e.code.lengths.max()) for e in encs)
+
+
+def test_huffman_decode_many_of_nothing():
+    assert H.decode_many([]) == []
+    assert H.lockstep_shape([]) == (0, 0, 0)
+
+
+@pytest.mark.parametrize("bits", [3, 5, 8])
+def test_huffman_cut_table_is_the_full_table_strided(bits):
+    """A table indexed by b bits holds entry j of the full one at
+    j << (MAX_LEN - b), for every b from the longest code to MAX_LEN."""
+    code = H.HuffmanCode.from_counts(
+        np.bincount(_skewed(5000, bits, 3.0, bits), minlength=1 << bits))
+    sym16, len16 = code.decode_table()
+    for b in range(int(code.lengths.max()), H.MAX_LEN + 1):
+        sym, ln = code.decode_table(b)
+        step = 1 << (H.MAX_LEN - b)
+        assert np.array_equal(sym, sym16[::step])
+        assert np.array_equal(ln, len16[::step])
 
 
 def test_huffman_near_entropy(rng):

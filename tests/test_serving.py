@@ -2,9 +2,11 @@
 quantize->Huffman->dequant path; response fidelity vs the exact cache."""
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.configs import SparKVConfig, get_smoke
+from repro.kernels.kv_dequant.ops import dequantize_chunks
 from repro.models import build_model
 from repro.serving.engine import SparKVServer
 
@@ -57,6 +59,30 @@ def test_streamed_bitstreams_roundtrip_exactly(server):
     scale_bound = max(np.abs(st.exact_k).max(),
                       np.abs(st.exact_v).max()) / 31
     assert err <= scale_bound * 2 + 1e-4
+
+
+def test_loaded_cache_is_the_stored_codes_assembled(server):
+    """The cache ``load_context`` builds from the decoded bitstreams is, bit
+    for bit, the one the stored codes give through the same dequant
+    launch, scatter into the exact cache and bfloat16 cast."""
+    srv, cid, _ = server
+    st = srv.contexts[cid]
+    cache, res = srv.load_context(cid, policy="cachegen")
+    streamed = sorted(res.engine.streamed_set)
+    assert len(streamed) == st.n_chunks
+    qts = [qt for c in streamed for qt in st.encoded[c][2:]]
+    outs = dequantize_chunks(qts, interpret=srv.interpret,
+                             out_dtype=jnp.float32)
+    k, v = st.exact_k.copy(), st.exact_v.copy()
+    ct = srv.chunk_tokens
+    for c, kd, vd in zip(streamed, outs[0::2], outs[1::2]):
+        k[c.l, 0, c.t * ct:(c.t + 1) * ct] = kd
+        v[c.l, 0, c.t * ct:(c.t + 1) * ct] = vd
+    for name, want in (("k", k), ("v", v)):
+        want = np.asarray(jnp.asarray(want, jnp.bfloat16))
+        got = np.asarray(cache[name])
+        assert got.dtype == want.dtype and got.shape == want.shape
+        assert np.array_equal(got.view(np.uint16), want.view(np.uint16))
 
 
 def test_utilization_tracking(server):

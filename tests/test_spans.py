@@ -79,11 +79,15 @@ def test_counters_come_from_the_planner_and_shapes(traced):
     assert plan[3] == {"streamed": res.n_streamed,
                        "computed": res.n_computed, "migrations": 0}
     plane = CHUNK * cfg.num_kv_heads * cfg.head_dim
-    codec = _named(spans, "sparkv.load.entropy_decode")
-    assert len(codec) == 2 * res.n_streamed
-    assert sum(sp[3]["values"] for sp in codec) == \
-        2 * res.n_streamed * plane
-    assert all(sp[3]["nbytes"] > 0 for sp in codec)
+    # one lockstep decode of every streamed K and V plane
+    (codec,) = _named(spans, "sparkv.load.entropy_decode")
+    planes = 2 * res.n_streamed
+    encs = [e for ek, ev, _, _ in st.encoded.values() for e in (ek, ev)]
+    assert codec[3] == {
+        "values": planes * plane,
+        "nbytes": sum(e.payload_bytes() for e in encs),
+        "planes": planes, "lanes": 64 * planes, "steps": -(-plane // 64),
+        "table_bits": max(int(e.code.lengths.max()) for e in encs)}
     (copy,) = _named(spans, "sparkv.load.copy_exact")
     assert copy[3] == {"nbytes": st.exact_k.nbytes + st.exact_v.nbytes}
     (dequant,) = _named(spans, "sparkv.load.dequant")
