@@ -5,5 +5,7 @@
 started on and prints one JSON line. Everything that belongs to one
 configuration, traffic mix, limit set or per-layer metric is a file of its
 own under ``configs/``, ``traffic/``, ``limits/`` or ``metrics/``, found
-by the name ``BENCHMARK.json`` gives it.
+by the name ``BENCHMARK.json`` gives it; a model family (weight tree,
+reference, operation counts) is a module under ``families/``, found by
+the configuration's ``"family"``.
 """

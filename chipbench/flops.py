@@ -3,21 +3,19 @@
 A multiply-add counts as two operations. Only the matrix products are
 counted (projections, attention scores and values, the output head):
 norms, rotary embedding and softmax are a rounding error beside them.
+What one token's forward and one streamed plane hold depends on the
+architecture, and the configuration's model family counts it
+(``spec.family``).
 """
 from __future__ import annotations
+
+from chipbench import spec
 
 
 def token_forward_flops(conf: dict, keys: int) -> float:
     """One token's forward through every layer and the output head, its
     attention reading ``keys`` cached positions (itself included)."""
-    d, f = conf["hidden_size"], conf["intermediate_size"]
-    hq, hkv = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = conf["head_dim"]
-    proj = 2 * d * (hq + 2 * hkv) * hd + 2 * hq * hd * d
-    attn = 2 * 2 * hq * hd * keys
-    mlp = 2 * 3 * d * f
-    return conf["num_hidden_layers"] * (proj + attn + mlp) \
-        + 2 * d * conf["vocab_size"]
+    return spec.family(conf).token_forward_flops(conf, keys)
 
 
 def decode_flops(conf: dict, context: int, first: int, steps: int) -> float:
@@ -38,5 +36,5 @@ def kv_dequant_rows(conf: dict, *, streamed: int, chunk_tokens: int,
                     group: int) -> int:
     """Rows of the one launch that dequantizes ``streamed`` chunks: a key
     and a value plane per chunk, one row per quantization group."""
-    plane = chunk_tokens * conf["num_key_value_heads"] * conf["head_dim"]
+    plane = spec.family(conf).kv_plane_values(conf, chunk_tokens)
     return streamed * 2 * (-(-plane // group))

@@ -15,8 +15,10 @@ The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1`` a
 ``breakdown``, ``readings`` (every number the check computes, compared or
 not), and last ``checks``: each number compared with its limit, which
-also end stderr. Exits non-zero and prints no result where JAX
-finds no TPU or fewer chips than the cell asks for.
+also end stderr. Exits non-zero and prints no result where the
+configuration's model family has no module or no model in the program
+(before JAX looks for a chip), or where JAX finds no TPU or fewer chips
+than the cell asks for.
 """
 import time
 
@@ -204,8 +206,9 @@ def main(argv=None) -> int:
 
     cell = spec.load_cell(ROOT, args.workload)
     try:
+        weights.program_config(cell.config)
         dev = device.require_chips(cell.workload["chips"])
-    except device.NoChip as e:
+    except (spec.NoFamily, device.NoChip) as e:
         _log(f"chipbench: {e}")
         return 2
     _log(f"device {dev}, compile cache {compile_cache()}")

@@ -1,11 +1,9 @@
 """flops.py against counts made by hand."""
 import json
-import math
 
-import jax
 import pytest
 
-from chipbench import flops, weights
+from chipbench import flops, spec
 from chipbench.tests.conftest import ROOT
 
 CONF = {"hidden_size": 8, "intermediate_size": 16, "num_attention_heads": 4,
@@ -42,13 +40,13 @@ def test_a_partial_group_is_one_row():
                                  group=8) == 2 * 2
 
 
-@pytest.mark.parametrize("name", ["sparkv-qwen3-4b", "qwen2.5-3b"])
+@pytest.mark.parametrize("name", sorted(
+    p.stem for p in (ROOT / "chipbench" / "configs").glob("*.json")))
 def test_published_token_flops_near_twice_the_parameters(name):
     with open(ROOT / "chipbench" / "configs" / f"{name}.json") as f:
         conf = json.load(f)
-    n = sum(math.prod(s) for s in jax.tree.leaves(
-        weights.shapes(conf), is_leaf=lambda x: isinstance(x, tuple)))
-    # every matrix is read once per token; norms and biases are not
+    n = spec.family(conf).token_params(conf)
+    # every matrix a token reads is read once; norms and biases are not
     # matrix products, attention over one key is a rounding error
     assert flops.token_forward_flops(conf, 1) == pytest.approx(2 * n,
                                                                rel=1e-3)

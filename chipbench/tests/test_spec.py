@@ -1,6 +1,8 @@
 """Discovery by name, and BENCHMARK.json against the rules it must keep:
 a new configuration, traffic mix or per-layer metric is a new file and a
-new entry, with no edit to the harness."""
+new entry, with no edit to the harness. A new architecture is a new
+``families/<name>.py`` and a configuration file whose ``"family"`` names
+it (``test_families.py``)."""
 import json
 import re
 import shutil
